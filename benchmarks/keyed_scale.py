@@ -1,7 +1,7 @@
 """Million-key keyed-state scaling sweep (docs/protocol.md §6).
 
-Zipf-skewed per-auction bid counting over key domains C ∈ {1e4, 1e6, 1e7}
-on 8- and 48-way ``--xla_force_host_platform_device_count`` meshes, comparing
+Zipf-skewed per-auction bid counting over key domains C ∈ {1e4, 1e6, 1e7},
+comparing
 
 * **sharded** — the hash-partitioned keyed dataplane
   (``launch.stream.build_keyed_pipeline``): each device owns a
@@ -18,8 +18,11 @@ at C=1e6 on 8 devices), so those rows carry ``skipped=1`` plus the byte
 estimates that ruled them out — the sharded rows at the same (C, S) complete,
 which is the point of the sweep.
 
-Each (C, S, mode) cell runs in a fresh subprocess because the virtual device
-count is fixed at jax import time (same pattern as the multidevice tests).
+On a TPU host every cell runs in this process on the real devices (a chip
+belongs to one process: a child could not reach it).  On a CPU host the mesh
+is 8 or 48 virtual devices, and each (C, S, mode) cell runs in a fresh child
+with ``JAX_PLATFORMS=cpu``, because the virtual device count is fixed at jax
+import time (same pattern as the multidevice tests).
 
 Usage: PYTHONPATH=src python -m benchmarks.keyed_scale  (or via benchmarks.run)
 """
@@ -73,16 +76,15 @@ def modeled_peak_bytes(mode: str, n_dev: int, keys: int, batches: int,
     return state_bytes + log_bytes + work
 
 
-def _worker(args) -> None:
-    """Runs in the subprocess (XLA_FLAGS set by the parent): one measured
-    cell, result as a ``KEYED_RESULT {...}`` JSON line on stdout."""
+def measure_cell(S: int, C: int, mode: str, nb: int, epb: int,
+                 key_skew: float = KEY_SKEW) -> dict:
+    """One measured cell on this process's ``S`` devices."""
     import time
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from repro import compat
     from repro.core import wcrdt as W
     from repro.core.window import as_assigner
     from repro.launch.mesh import make_data_mesh
@@ -92,15 +94,14 @@ def _worker(args) -> None:
     )
     from repro.streaming.generator import NexmarkConfig, generate_log
 
-    S, C, nb, epb = args.n_dev, args.keys, args.batches, args.epb
     assert len(jax.devices()) == S, (len(jax.devices()), S)
     nx = NexmarkConfig(num_partitions=S, num_batches=nb, events_per_batch=epb,
-                       num_auctions=C, key_skew=args.key_skew)
+                       num_auctions=C, key_skew=key_skew)
     log = generate_log(nx)
     horizon = nb * nx.batch_span_ms
     rounds = max(nb // SYNC_EVERY, 1)
 
-    if args.mode == "sharded":
+    if mode == "sharded":
         shards = W.KeyShards(C, S)
         mesh = make_data_mesh(S)
         assigner = as_assigner(WINDOW_LEN, WINDOW_LEN // 2)
@@ -132,7 +133,7 @@ def _worker(args) -> None:
             "width": shards.width,
         }
     else:  # dense
-        mesh = compat.make_mesh((S,), ("data",))
+        mesh = make_data_mesh(S)
         query = MAKERS["q5"](S, window_len=WINDOW_LEN, num_slots=NUM_SLOTS,
                              num_auctions=C)
         first, n_win = read_window_range(query, horizon)
@@ -155,11 +156,20 @@ def _worker(args) -> None:
             "ok_windows": int(np.asarray(oks)[0].sum()),
             "width": C,
         }
-    print("KEYED_RESULT " + json.dumps(out))
+    return out
+
+
+def _on_tpu() -> bool:
+    import jax
+
+    return jax.default_backend() == "tpu"
 
 
 def _run_cell(n_dev: int, keys: int, mode: str, batches: int, epb: int) -> dict:
+    if _on_tpu():
+        return measure_cell(n_dev, keys, mode, batches, epb)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
     cmd = [
         sys.executable, "-m", "benchmarks.keyed_scale", "--worker",
@@ -184,10 +194,13 @@ def _label(keys: int, n_dev: int) -> str:
 def main(quick: bool = False) -> None:
     from benchmarks.common import timer
 
+    import jax
+
     batches, epb = (8, 128) if quick else (8, 256)
+    meshes = (len(jax.devices()),) if _on_tpu() else MESH_SIZES
     state_by_c: dict[int, dict[int, float]] = {}
     for keys in KEY_DOMAINS:
-        for n_dev in MESH_SIZES:
+        for n_dev in meshes:
             lbl = _label(keys, n_dev)
             with timer() as tm:
                 res = _run_cell(n_dev, keys, "sharded", batches, epb)
@@ -261,7 +274,9 @@ if __name__ == "__main__":
     ap.add_argument("--quick", action="store_true")
     args = ap.parse_args()
     if args.worker:
-        _worker(args)
+        res = measure_cell(args.n_dev, args.keys, args.mode, args.batches,
+                           args.epb, args.key_skew)
+        print("KEYED_RESULT " + json.dumps(res))
     else:
         print("name,us_per_call,derived")
         main(quick=args.quick)

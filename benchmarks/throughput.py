@@ -29,12 +29,12 @@ def real_dataplane_rate(
     full-replica bytes a full-state round would ship — the delta's comparand,
     a constant of the query's specs — and the device's input-log bytes, so
     rows can report a modeled peak of state + resident log)."""
-    from repro import compat
+    from repro.launch.mesh import make_data_mesh
     from repro.core import wcrdt as W
     from repro.launch.stream import MAKERS, build_pipeline, read_window_range
 
     n_dev = 1
-    mesh = compat.make_mesh((n_dev,), ("data",))
+    mesh = make_data_mesh(n_dev)
     nx = NexmarkConfig(num_partitions=n_dev, num_batches=batches, events_per_batch=epb)
     log = generate_log(nx)
     kw = {"hop": hop} if hop else {}

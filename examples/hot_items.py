@@ -27,7 +27,7 @@ def main(argv=None):
     import jax
     import numpy as np
 
-    from repro import compat
+    from repro.launch.mesh import make_data_mesh
     from repro.launch.stream import build_pipeline, read_window_range
     from repro.runtime import SimConfig, run_holon
     from repro.streaming import NexmarkConfig, generate_log, make_q5
@@ -59,7 +59,7 @@ def main(argv=None):
 
     # --- shard_map dataplane ---------------------------------------------
     n_dev = len(jax.devices())
-    mesh = compat.make_mesh((n_dev,), ("data",))
+    mesh = make_data_mesh(n_dev)
     dnx = NexmarkConfig(num_partitions=n_dev, num_batches=32, events_per_batch=1024)
     dlog = generate_log(dnx)
     dq = make_q5(n_dev, window_len=args.window_len, num_slots=64, hop=args.hop)

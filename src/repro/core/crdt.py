@@ -31,6 +31,8 @@ from repro.core.lattice import (
 )
 
 NEG_INF = jnp.float32(-jnp.inf)
+# float_to_ordered_u32(-inf): the TopK padding value in the sort-key domain
+_NEG_INF_KEY = 0x007FFFFF
 
 
 def _masked(vals: jax.Array, mask: jax.Array, fill) -> jax.Array:
@@ -286,22 +288,25 @@ def _topk_join_sorted(vals_a, ids_a, vals_b, ids_b, k: int):
     """Join two top-k sets (desc-sorted, -inf padded) into the top-k union.
 
     Set semantics: exact (val, id) duplicates collapse, so the join is
-    idempotent.  Uses lax.sort with two keys for lexicographic order.
+    idempotent.  Order and duplicates are decided on the ordered-u32 bit
+    pattern of ``val`` (as ``LWWReg`` does), never on float comparison: a
+    backend that flushes subnormals compares 4e-45 equal to 0.0, and a float
+    sort would then keep whichever of the two came first.
     """
-    vals = jnp.concatenate([vals_a, vals_b], axis=-1)
+    keys = float_to_ordered_u32(jnp.concatenate([vals_a, vals_b], axis=-1))
     ids = jnp.concatenate([ids_a, ids_b], axis=-1)
-    # ascending lexicographic sort by (val, id)
-    svals, sids = lax.sort((vals, ids), dimension=-1, num_keys=2)
+    # ascending lexicographic sort by (val bits, id)
+    skeys, sids = lax.sort((keys, ids), dimension=-1, num_keys=2)
     # mark duplicates of their left neighbour
-    dup = jnp.zeros(svals.shape, dtype=bool)
+    dup = jnp.zeros(skeys.shape, dtype=bool)
     dup = dup.at[..., 1:].set(
-        (svals[..., 1:] == svals[..., :-1]) & (sids[..., 1:] == sids[..., :-1])
+        (skeys[..., 1:] == skeys[..., :-1]) & (sids[..., 1:] == sids[..., :-1])
     )
-    svals = jnp.where(dup, -jnp.inf, svals)
+    skeys = jnp.where(dup, _NEG_INF_KEY, skeys)
     sids = jnp.where(dup, 0, sids)
-    svals, sids = lax.sort((svals, sids), dimension=-1, num_keys=2)
+    skeys, sids = lax.sort((skeys, sids), dimension=-1, num_keys=2)
     # top-k = last k ascending, reversed to descending
-    top_v = svals[..., -k:][..., ::-1]
+    top_v = ordered_u32_to_float(skeys[..., -k:][..., ::-1])
     top_i = sids[..., -k:][..., ::-1]
     return top_v, top_i
 

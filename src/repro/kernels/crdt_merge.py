@@ -58,22 +58,25 @@ def crdt_merge_pallas(
 # contents.  Joining R such deltas per slot means: replicas whose tenant
 # window trails the per-slot max (stale tenants and clean slots alike) must
 # NOT contribute — their content belongs to an older window.  The kernel
-# loads a [R, tile_w, tile_f] block plus its [R, tile_w] wid block, computes
-# the per-slot winner mask on the VPU, and reduces gated lanes in registers.
-# Blocks whose every slot is clean skip the masked reduce entirely and copy
-# replica 0 (all deltas hold the identical deterministic zero-state there).
+# loads a [R, tile_w, tile_f] block plus its [R, tile_w, 1] wid column,
+# computes the per-slot winner mask on the VPU, and reduces gated lanes in
+# registers.  Slots sit on sublanes and features on lanes, so the wid column
+# broadcasts along lanes (a [R, tile_w] wid row would put slots on lanes,
+# which the TPU's (8, 128) block rule refuses for tile_w < 128).  Blocks whose
+# every slot is clean skip the masked reduce entirely and copy replica 0 (all
+# deltas hold the identical deterministic zero-state there).
 # ---------------------------------------------------------------------------
 
 
 def _gated_kernel(wid_ref, stack_ref, out_ref, *, op: str):
-    wid = wid_ref[...]  # i32[R, tile_w]
-    top = jnp.max(wid, axis=0)  # i32[tile_w]
+    wid = wid_ref[...]  # i32[R, tile_w, 1]
+    top = jnp.max(wid, axis=0)  # i32[tile_w, 1]
     any_dirty = jnp.max(top) >= 0
 
     @pl.when(any_dirty)
     def _dirty():
         x = stack_ref[...]  # [R, tile_w, tile_f]
-        gate = (wid == top[None, :])[..., None]  # [R, tile_w, 1]
+        gate = wid == top[None]  # [R, tile_w, 1]
         xg = jnp.where(gate, x, gated_neutral(op, x.dtype))
         if op == "max":
             out_ref[...] = jnp.max(xg, axis=0)
@@ -101,18 +104,26 @@ def gated_delta_merge_pallas(
     tile_f: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
+    """``[W, F]`` gated join of ``R`` delta replicas.
+
+    ``tile_w`` must divide ``W`` and be a multiple of 8 or equal ``W``;
+    ``tile_f`` must divide ``F`` and be a multiple of 128 or equal ``F``
+    (the TPU block rule).  Legal inside ``shard_map``: the output carries
+    the inputs' device-variance (vma), as ``check_vma`` requires.
+    """
     R, W, F = stack.shape
     assert wid_stack.shape == (R, W), (wid_stack.shape, stack.shape)
     assert W % tile_w == 0 and F % tile_f == 0, (W, F, tile_w, tile_f)
+    vma = jax.typeof(wid_stack).vma | jax.typeof(stack).vma
     grid = (W // tile_w, F // tile_f)
     return pl.pallas_call(
         functools.partial(_gated_kernel, op=op),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((R, tile_w), lambda i, j: (0, i)),
+            pl.BlockSpec((R, tile_w, 1), lambda i, j: (0, i, 0)),
             pl.BlockSpec((R, tile_w, tile_f), lambda i, j: (0, i, j)),
         ],
         out_specs=pl.BlockSpec((tile_w, tile_f), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((W, F), stack.dtype),
+        out_shape=jax.ShapeDtypeStruct((W, F), stack.dtype, vma=vma),
         interpret=interpret,
-    )(wid_stack, stack)
+    )(wid_stack[..., None], stack)

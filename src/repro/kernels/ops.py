@@ -121,7 +121,7 @@ def gated_delta_merge(
     trailing = leaf_stack.shape[2:]
     flat = leaf_stack.reshape(R, W, -1)
     F = flat.shape[2]
-    tile_w = 8 if W % 8 == 0 else 1
+    tile_w = 8 if W % 8 == 0 else W  # (8, 128) rule: a multiple of 8, or all
     tile_f = 128
     pad_f = (-F) % tile_f
     if pad_f:

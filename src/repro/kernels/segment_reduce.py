@@ -12,10 +12,11 @@ few thousand.  This kernel is the million-key replacement (DESIGN.md §5):
      tile ``(start, count)`` event ranges, shipped as scalar-prefetch args,
   3. the kernel grid runs one program per *segment tile* of ``seg_tile``
      outputs; each program walks only its own event range in fixed ``bt``
-     chunks (dynamic ``pl.ds`` loads from the VMEM-resident sorted stream)
-     and reduces each chunk against a ``[bt, seg_tile]`` relative one-hot.
+     chunks (dynamic ``pl.ds`` loads, aligned down to a ``bt`` boundary,
+     from the VMEM-resident ``[L, 1]`` sorted column) and reduces each chunk
+     against a ``[bt, seg_tile]`` relative one-hot.
 
-Work is O(events · seg_tile / bt) + one partial chunk per non-empty tile —
+Work is O(events · seg_tile / bt) + two partial chunks per non-empty tile —
 independent of total C — and VMEM holds one ``[seg_tile]`` accumulator
 instead of the whole ``[W, C]`` state, so the output can be arbitrarily
 large (it streams through HBM tile by tile).  Empty tiles never enter the
@@ -44,32 +45,32 @@ def _kernel(
 ):
     j = pl.program_id(0)
     base = start_ref[j]
-    cnt = count_ref[j]
+    end = base + count_ref[j]
+    # chunks start on a bt boundary (the TPU compiler only accepts dynamic
+    # slices it can prove aligned); lanes of the first chunk before ``base``
+    # and of the last past ``end`` belong to other tiles and are masked
+    lo = (base // bt) * bt
     tile_lo = j * seg_tile
     neutral = jnp.float32(NEUTRAL[op])
 
     def chunk(i, acc):
-        off = base + i * bt
-        v = vals_ref[pl.ds(off, bt)].astype(jnp.float32)
+        off = pl.multiple_of(lo + i * bt, bt)
+        v = vals_ref[pl.ds(off, bt), :]  # [bt, 1]
         if op == "count":
             v = jnp.ones_like(v)
-        sg = segs_ref[pl.ds(off, bt)]
-        # lanes beyond the range's end are padding (sentinel segments would
-        # mask them too, but the explicit bound keeps the last chunk exact)
-        live = (jax.lax.broadcasted_iota(jnp.int32, (bt, seg_tile), 0) + i * bt) < cnt
-        rel = sg - tile_lo
-        oh = (
-            rel[:, None] == jax.lax.broadcasted_iota(jnp.int32, (bt, seg_tile), 1)
-        ) & live
-        contrib = jnp.where(oh, v[:, None], neutral)
+        pos = off + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+        live = (pos >= base) & (pos < end)
+        rel = segs_ref[pl.ds(off, bt), :] - tile_lo
+        oh = (rel == jax.lax.broadcasted_iota(jnp.int32, (bt, seg_tile), 1)) & live
+        contrib = jnp.where(oh, v, neutral)
         if op in ("sum", "count"):
-            return acc + jnp.sum(contrib, axis=0)
+            return acc + jnp.sum(contrib, axis=0, keepdims=True)
         if op == "max":
-            return jnp.maximum(acc, jnp.max(contrib, axis=0))
-        return jnp.minimum(acc, jnp.min(contrib, axis=0))
+            return jnp.maximum(acc, jnp.max(contrib, axis=0, keepdims=True))
+        return jnp.minimum(acc, jnp.min(contrib, axis=0, keepdims=True))
 
-    acc0 = jnp.full((seg_tile,), neutral, dtype=jnp.float32)
-    n_chunks = pl.cdiv(cnt, bt)
+    acc0 = jnp.full((1, seg_tile), neutral, dtype=jnp.float32)
+    n_chunks = jnp.where(end > base, pl.cdiv(end - lo, bt), 0)
     out_ref[...] = jax.lax.fori_loop(0, n_chunks, chunk, acc0)
 
 
@@ -96,26 +97,28 @@ def segment_reduce_pallas(
     sentinel = jnp.int32(n_seg_pad)  # beyond every tile: masked lanes sort last
     seg_m = jnp.where(mask, segs.astype(jnp.int32), sentinel)
     sseg, sval = jax.lax.sort_key_val(seg_m, vals.astype(jnp.float32))
-    # pad by one chunk so the last dynamic load never runs off the stream
-    sseg = jnp.pad(sseg, (0, bt), constant_values=n_seg_pad)
-    sval = jnp.pad(sval, (0, bt))
-    bounds = jnp.arange(n_tiles + 1, dtype=jnp.int32) * seg_tile
-    edges = jnp.searchsorted(sseg[: B], bounds, side="left").astype(jnp.int32)
+    # pad to whole chunks so the last aligned load never runs off the stream;
+    # the stream is a [L, 1] column (events on sublanes), VMEM-resident
+    L = pl.cdiv(B, bt) * bt
+    edges = jnp.searchsorted(
+        sseg, jnp.arange(n_tiles + 1, dtype=jnp.int32) * seg_tile, side="left"
+    ).astype(jnp.int32)
     starts, counts = edges[:-1], edges[1:] - edges[:-1]
+    col = lambda x, fill: jnp.pad(x, (0, L - B), constant_values=fill).reshape(L, 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((B + bt,), lambda j, *_: (0,)),
-            pl.BlockSpec((B + bt,), lambda j, *_: (0,)),
+            pl.BlockSpec((L, 1), lambda j, *_: (0, 0)),
+            pl.BlockSpec((L, 1), lambda j, *_: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((seg_tile,), lambda j, *_: (j,)),
+        out_specs=pl.BlockSpec((1, seg_tile), lambda j, *_: (0, j)),
     )
     out = pl.pallas_call(
         functools.partial(_kernel, op=op, seg_tile=seg_tile, bt=bt),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_seg_pad,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, n_seg_pad), jnp.float32),
         interpret=interpret,
-    )(starts, counts, sval, sseg)
-    return out[:n_seg]
+    )(starts, counts, col(sval, 0.0), col(sseg, n_seg_pad))
+    return out[0, :n_seg]

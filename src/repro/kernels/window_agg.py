@@ -13,8 +13,11 @@ Grid: one program per event tile of ``bt`` events; the [W(,C)] window state
 stays resident in VMEM across the whole grid (accumulator revisiting), so
 HBM traffic is events-in + state once.
 
-Tiling notes: bt is a multiple of 8 (sublane), W·C lanes padded to 128 by the
-caller (ops.py); fp32 accumulation.
+Tiling: events arrive as ``[bt, 1]`` column tiles (events on sublanes, bt a
+multiple of 8), so every per-event operand broadcasts along lanes against the
+``[bt, W]`` / ``[bt, C]`` one-hots without a reshape inside the kernel — the
+TPU compiler refuses a 1-D ``[bt]`` block cast to ``[bt, 1]``.  The mask
+travels as i32 (no i1 memrefs); fp32 accumulation.
 """
 from __future__ import annotations
 
@@ -27,6 +30,11 @@ from jax.experimental import pallas as pl
 NEUTRAL = {"sum": 0.0, "count": 0.0, "max": -jnp.inf, "min": jnp.inf}
 
 
+def _tile_values(vals_ref, op: str):
+    v = vals_ref[...].astype(jnp.float32)  # [bt, 1]
+    return jnp.ones_like(v) if op == "count" else v
+
+
 def _kernel_unkeyed(vals_ref, slots_ref, mask_ref, out_ref, *, op: str, W: int):
     i = pl.program_id(0)
 
@@ -34,21 +42,18 @@ def _kernel_unkeyed(vals_ref, slots_ref, mask_ref, out_ref, *, op: str, W: int):
     def _init():
         out_ref[...] = jnp.full_like(out_ref, NEUTRAL[op])
 
-    v = vals_ref[...].astype(jnp.float32)  # [bt]
-    if op == "count":
-        v = jnp.ones_like(v)
-    m = mask_ref[...]
-    slots = slots_ref[...]
-    onehot = slots[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
-    onehot = onehot & m[:, None]  # [bt, W]
+    v = _tile_values(vals_ref, op)
+    bt = v.shape[0]
+    onehot = (slots_ref[...] == jax.lax.broadcasted_iota(jnp.int32, (bt, W), 1)) & (
+        mask_ref[...] != 0
+    )  # [bt, W]
+    tile = jnp.where(onehot, v, NEUTRAL[op])
     if op in ("sum", "count"):
-        out_ref[...] += jnp.sum(jnp.where(onehot, v[:, None], 0.0), axis=0)
+        out_ref[...] += jnp.sum(tile, axis=0, keepdims=True)
     elif op == "max":
-        tile = jnp.max(jnp.where(onehot, v[:, None], -jnp.inf), axis=0)
-        out_ref[...] = jnp.maximum(out_ref[...], tile)
+        out_ref[...] = jnp.maximum(out_ref[...], jnp.max(tile, axis=0, keepdims=True))
     else:
-        tile = jnp.min(jnp.where(onehot, v[:, None], jnp.inf), axis=0)
-        out_ref[...] = jnp.minimum(out_ref[...], tile)
+        out_ref[...] = jnp.minimum(out_ref[...], jnp.min(tile, axis=0, keepdims=True))
 
 
 def _kernel_keyed(vals_ref, slots_ref, keys_ref, mask_ref, out_ref, *, op: str, W: int, C: int):
@@ -59,20 +64,19 @@ def _kernel_keyed(vals_ref, slots_ref, keys_ref, mask_ref, out_ref, *, op: str, 
     def _init():
         out_ref[...] = jnp.full_like(out_ref, NEUTRAL[op])
 
-    v = vals_ref[...].astype(jnp.float32)
-    if op == "count":
-        v = jnp.ones_like(v)
-    m = mask_ref[...]
-    slots, keys = slots_ref[...], keys_ref[...]
+    v = _tile_values(vals_ref, op)
     bt = v.shape[0]
-    oh_w = (slots[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)) & m[:, None]
-    oh_c = keys[:, None] == jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+    slots, live = slots_ref[...], mask_ref[...] != 0  # [bt, 1]
+    oh_c = keys_ref[...] == jax.lax.broadcasted_iota(jnp.int32, (bt, C), 1)
     if op in ("sum", "count"):
-        rhs = jnp.where(oh_c, v[:, None], 0.0)  # [bt, C]
+        oh_w = (slots == jax.lax.broadcasted_iota(jnp.int32, (bt, W), 1)) & live
+        rhs = jnp.where(oh_c, v, 0.0)  # [bt, C]
         out_ref[...] += jax.lax.dot_general(
             oh_w.astype(jnp.float32),
             rhs,
             (((0,), (0,)), ((), ())),
+            # f32 passes on the MXU: the default rounds v to bf16
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
     else:
@@ -80,11 +84,12 @@ def _kernel_keyed(vals_ref, slots_ref, keys_ref, mask_ref, out_ref, *, op: str, 
         # live intermediate is [bt, C], never the [bt, W, C] broadcast that
         # would OOM at moderate C (peak pinned by tests/test_segment_reduce.py)
         for w in range(W):
-            strip = jnp.where(oh_w[:, w][:, None] & oh_c, v[:, None], NEUTRAL[op])
+            strip = jnp.where((slots == w) & live & oh_c, v, NEUTRAL[op])
+            row = out_ref[pl.ds(w, 1), :]
             if op == "max":
-                out_ref[w, :] = jnp.maximum(out_ref[w, :], jnp.max(strip, axis=0))
+                out_ref[pl.ds(w, 1), :] = jnp.maximum(row, jnp.max(strip, axis=0, keepdims=True))
             else:
-                out_ref[w, :] = jnp.minimum(out_ref[w, :], jnp.min(strip, axis=0))
+                out_ref[pl.ds(w, 1), :] = jnp.minimum(row, jnp.min(strip, axis=0, keepdims=True))
 
 
 def window_agg_pallas(
@@ -117,25 +122,24 @@ def window_agg_pallas(
             keys = jnp.pad(keys, (0, pad))
         B += pad
     grid = (B // block_b,)
-    ev_spec = pl.BlockSpec((block_b,), lambda i: (i,))
+    col = lambda x: x.reshape(B, 1)
+    ev_spec = pl.BlockSpec((block_b, 1), lambda i: (i, 0))
+    ev = [col(vals), col(slots.astype(jnp.int32))]
+    if keys is not None:
+        ev.append(col(keys.astype(jnp.int32)))
+    ev.append(col(mask.astype(jnp.int32)))
     if keys is None:
-        out_spec = pl.BlockSpec((W,), lambda i: (0,))
         fn = functools.partial(_kernel_unkeyed, op=op, W=W)
-        return pl.pallas_call(
-            fn,
-            grid=grid,
-            in_specs=[ev_spec, ev_spec, ev_spec],
-            out_specs=out_spec,
-            out_shape=jax.ShapeDtypeStruct((W,), jnp.float32),
-            interpret=interpret,
-        )(vals, slots, mask)
-    out_spec = pl.BlockSpec((W, C), lambda i: (0, 0))
-    fn = functools.partial(_kernel_keyed, op=op, W=W, C=C)
-    return pl.pallas_call(
+        out_shape = (1, W)
+    else:
+        fn = functools.partial(_kernel_keyed, op=op, W=W, C=C)
+        out_shape = (W, C)
+    out = pl.pallas_call(
         fn,
         grid=grid,
-        in_specs=[ev_spec, ev_spec, ev_spec, ev_spec],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((W, C), jnp.float32),
+        in_specs=[ev_spec] * len(ev),
+        out_specs=pl.BlockSpec(out_shape, lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
         interpret=interpret,
-    )(vals, slots, keys, mask)
+    )(*ev)
+    return out[0] if keys is None else out

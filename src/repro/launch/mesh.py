@@ -6,7 +6,7 @@ XLA_FLAGS before any jax initialization.
 """
 from __future__ import annotations
 
-from repro import compat
+import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,18 +15,20 @@ def make_production_mesh(*, multi_pod: bool = False):
     the leading axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_data_mesh(n_dev: int | None = None):
     """1-D ``data`` mesh over all (or the first ``n_dev``) devices — the
     shape the keyed/sharded dataplane runs on (docs/protocol.md §6): one
     owner shard per device, no model axis."""
-    import jax
-
     n = n_dev if n_dev is not None else len(jax.devices())
-    return compat.make_mesh((n,), ("data",))
+    return make_mesh((n,), ("data",))
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return compat.make_mesh(shape, axes)
+    """Mesh with Auto axes: jax.make_mesh defaults to Explicit, which would
+    turn on sharding-in-types for every program built on it."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )

@@ -33,9 +33,10 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core import wcrdt as W
 from repro.core.window import as_assigner
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_data_mesh
 from repro.obs.timing import WallTimer
 from repro.streaming.events import KIND_BID, EventBatch
 from repro.streaming.generator import NexmarkConfig, batch_watermark, generate_log
@@ -50,6 +51,12 @@ from repro.streaming.queries import (
 
 # every query the benchmarks import is runnable on the dataplane, including
 # the shared-state-free q0 (sync rounds no-op) and the sliding-window q5
+def _vary(tree):
+    """Mark freshly built (device-invariant) replica state as varying over
+    ``data`` — what shard_map's vma check requires of a per-device carry."""
+    return jax.tree.map(lambda x: lax.pcast(x, ("data",), to="varying"), tree)
+
+
 MAKERS = {
     "q0": make_q0,
     "q1_ratio": make_q1_ratio,
@@ -79,11 +86,10 @@ def build_pipeline(
     def node_fn(log: EventBatch):
         p = jax.lax.axis_index("data")
         # mark replica state device-varying from the start (shard_map vma)
-        vary = lambda t: jax.tree.map(lambda x: compat.pvary(x, ("data",)), t)
-        shared = vary(query.init_shared())
-        local = vary(query.init_local())
+        shared = _vary(query.init_shared())
+        local = _vary(query.init_local())
         baselines = tuple(W.baseline_of(st) for st in shared)
-        sync_bytes = compat.pvary(jnp.float32(0.0), ("data",))
+        sync_bytes = _vary(jnp.float32(0.0))
 
         def fold_one(carry, batch):
             # batch_idx advances the folded frontier — what delta_since diffs
@@ -118,7 +124,7 @@ def build_pipeline(
             ),
             log0,
         )
-        idx0 = compat.pvary(jnp.int32(0), ("data",))
+        idx0 = _vary(jnp.int32(0))
         (shared, local, _, _, sync_bytes), _ = jax.lax.scan(
             sync_chunk, (shared, local, idx0, baselines, sync_bytes), chunked
         )
@@ -132,7 +138,7 @@ def build_pipeline(
 
     log_specs = jax.tree.map(lambda _: P("data"), EventBatch(*([0] * 7)))
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             node_fn,
             mesh=mesh,
             in_specs=(log_specs,),
@@ -201,10 +207,9 @@ def build_keyed_pipeline(
 
     def node_fn(log: EventBatch, key_table, sched, wm_sync):
         me = jax.lax.axis_index("data")
-        vary = lambda t: jax.tree.map(lambda x: compat.pvary(x, ("data",)), t)
-        state = vary(spec.zero())
+        state = _vary(spec.zero())
         log0 = jax.tree.map(lambda x: x[0], log)  # [num_batches, B] leaves
-        table0 = compat.pvary(key_table[0], ("data",))  # u32 [width]
+        table0 = key_table[0]  # u32 [width]; in_specs already mark it varying
         B = log0.ts.shape[1]
         rows = jnp.arange(S, dtype=jnp.int32)[:, None]  # [S, 1]
         a2a = lambda x: jax.lax.all_to_all(
@@ -262,10 +267,8 @@ def build_keyed_pipeline(
             .reshape(n_rounds, sync_every, S)
             .astype(jnp.int32)
         )
-        zero = compat.pvary(jnp.float32(0.0), ("data",))
-        prov0 = compat.pvary(
-            jnp.full((S,), -(2**31), jnp.int32), ("data",)
-        )
+        zero = _vary(jnp.float32(0.0))
+        prov0 = _vary(jnp.full((S,), -(2**31), jnp.int32))
         (state, shuffle_bytes, sync_bytes, prov), _ = jax.lax.scan(
             sync_round, (state, zero, zero, prov0), (chunks, wm_sync[:n_rounds])
         )
@@ -286,7 +289,7 @@ def build_keyed_pipeline(
     n_out = 5 if provenance else 4
     log_specs = jax.tree.map(lambda _: P("data"), EventBatch(*([0] * 7)))
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             node_fn,
             mesh=mesh,
             in_specs=(log_specs, P("data"), P(), P()),
@@ -334,8 +337,10 @@ def main(argv=None):
     if not 1 <= args.sync_every <= args.batches:
         ap.error(f"--sync-every must be in [1, --batches]; got {args.sync_every}")
 
-    n_dev = len(jax.devices())
-    mesh = compat.make_mesh((n_dev,), ("data",))
+    enable_compile_cache()
+    devices = jax.devices()
+    n_dev = len(devices)
+    mesh = make_data_mesh(n_dev)
     nx = NexmarkConfig(
         num_partitions=n_dev,
         num_batches=args.batches,
@@ -365,7 +370,10 @@ def main(argv=None):
     rounds = max(args.batches // args.sync_every, 1)
     sync_per_round = float(np.asarray(sb).mean()) / rounds
     a = query.assigner
+    # the device is named on the same line as the rate: a CPU run's
+    # throughput is never mistaken for the chip's
     print(
+        f"platform={devices[0].platform} device_kind={devices[0].device_kind} "
         f"devices={n_dev} events={total_events} wall={dt*1e3:.1f}ms "
         f"throughput={total_events/dt/1e6:.2f}M ev/s "
         f"window={a.window_len}/hop={a.hop} complete_windows={done} "

@@ -26,7 +26,6 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 
 from repro.models.common import dense_init, shard
 
@@ -109,7 +108,7 @@ def _dispatch_gather(xg_pad: jax.Array, tok_of_slot: jax.Array) -> jax.Array:
         idx = jnp.arange(gl)[:, None]
         return xg_l[idx, tok_l]  # [g_loc, slots_loc, D]
 
-    return compat.shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(dp, None, None), P(dp, "model")),
@@ -138,7 +137,7 @@ def _combine_scatter(y_flat: jax.Array, tok_of_slot: jax.Array, Tl: int) -> jax.
         out = jnp.zeros((gl, Tl + 1, D), y_l.dtype).at[idx, tok_l].add(y_l)
         return jax.lax.psum(out, "model")
 
-    return compat.shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(dp, "model", None), P(dp, "model")),

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import wcrdt as W
 from repro.core import wgcounter, wtopk
@@ -259,12 +259,12 @@ _MULTIDEV_SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, numpy as np
-from repro import compat
+from repro.launch.mesh import make_data_mesh
 from repro.launch.stream import MAKERS, build_pipeline
 from repro.streaming import NexmarkConfig, generate_log
 
 n_dev = len(jax.devices()); assert n_dev == 4, n_dev
-mesh = compat.make_mesh((n_dev,), ("data",))
+mesh = make_data_mesh(n_dev)
 nx = NexmarkConfig(num_partitions=n_dev, num_batches=16, events_per_batch=512)
 log = generate_log(nx)
 for qn in ("q1_ratio", "q7"):

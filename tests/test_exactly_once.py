@@ -6,7 +6,7 @@ keeps making progress as long as one node survives.
 """
 import numpy as np
 import pytest
-from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.runtime import FailureScenario, SimConfig, run_flink, run_holon
 from repro.streaming import generate_log, make_q1_ratio, make_q4, make_q7, NexmarkConfig

@@ -4,7 +4,7 @@ graceful-handoff cheapness, and membership-epoch plumbing."""
 import dataclasses
 
 import numpy as np
-from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.runtime import Scenario, SimConfig, assignment, run_holon
 from repro.runtime.harness import HolonHarness

@@ -151,12 +151,12 @@ def test_q0_harness_still_matches_oracle():
 
 
 def _dataplane_case(query_name: str, hop: int | None, delta_sync: bool = True):
-    from repro import compat
+    from repro.launch.mesh import make_data_mesh
     from repro.launch.stream import MAKERS, build_pipeline, read_window_range
 
     n_dev = 1
     batches, epb = 32, 1024
-    mesh = compat.make_mesh((n_dev,), ("data",))
+    mesh = make_data_mesh(n_dev)
     nx = NexmarkConfig(num_partitions=n_dev, num_batches=batches,
                        events_per_batch=epb)
     log = generate_log(nx)
@@ -204,12 +204,12 @@ def test_q5_dataplane_multidevice_subprocess():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, numpy as np
-from repro import compat
+from repro.launch.mesh import make_data_mesh
 from repro.launch.stream import MAKERS, build_pipeline, read_window_range
 from repro.streaming import NexmarkConfig, generate_log
 
 n_dev = len(jax.devices()); assert n_dev == 4, n_dev
-mesh = compat.make_mesh((n_dev,), ("data",))
+mesh = make_data_mesh(n_dev)
 nx = NexmarkConfig(num_partitions=n_dev, num_batches=24, events_per_batch=512)
 log = generate_log(nx)
 q = MAKERS["q5"](n_dev, window_len=200, num_slots=64)

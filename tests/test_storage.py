@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import numpy as np
 import pytest
-from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.runtime.storage import CheckpointStorage, PartitionCheckpoint, _coverage
 

@@ -1,0 +1,131 @@
+"""Ahead-of-time compiles for a described TPU v5e:2x2 — nothing runs.
+
+The TPU compiler is installed even where no chip is attached, and it refuses
+what interpret mode accepts: unaligned blocks, unaligned dynamic slices, 1-D
+event blocks reshaped inside a kernel, a ``pallas_call`` whose output lacks
+the device-variance ``shard_map`` checks.  These compiles pin each Pallas
+kernel at real widths and the delta-sync dataplane (the path that carries
+``gated_delta_merge``) on a four-chip mesh.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and the test workers all import
+this file.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+B, W = 4096, 64  # event lanes per fold call, ring slots
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to rehearse
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # compiles for a described chip cannot be read back from a persistent
+    # cache without one; keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args):
+    """Compiled HLO text; raises what the chip's compiler would raise."""
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def test_gated_delta_merge_compiles(one_chip):
+    from repro.kernels.ops import gated_delta_merge
+
+    R, F = 4, 256
+    text = _compile(
+        lambda w, x: gated_delta_merge(w, x, op="max", use_pallas=True),
+        jax.ShapeDtypeStruct((R, W), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((R, W, F), jnp.float32, sharding=one_chip),
+    )
+    assert "tpu_custom_call" in text
+
+
+def _events(one_chip, *dtypes):
+    """One ``[B]`` event-lane operand per dtype."""
+    return [jax.ShapeDtypeStruct((B,), dt, sharding=one_chip) for dt in dtypes]
+
+
+@pytest.mark.parametrize("op", ["sum", "max"])
+@pytest.mark.parametrize("keyed", [False, True])
+def test_window_agg_compiles(one_chip, op, keyed):
+    from repro.kernels.window_agg import window_agg_pallas
+
+    C = 128
+    if keyed:
+        fn = lambda v, s, k, m: window_agg_pallas(v, s, m, W, op=op, keys=k, C=C)
+        args = _events(one_chip, jnp.float32, jnp.int32, jnp.int32, jnp.bool_)
+    else:
+        fn = lambda v, s, m: window_agg_pallas(v, s, m, W, op=op)
+        args = _events(one_chip, jnp.float32, jnp.int32, jnp.bool_)
+    assert "tpu_custom_call" in _compile(fn, *args)
+
+
+def test_topk_window_compiles(one_chip):
+    from repro.kernels.topk_window import topk_window_pallas
+
+    k = 8
+    state = [
+        jax.ShapeDtypeStruct((W, k), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((W, k), jnp.uint32, sharding=one_chip),
+    ]
+    ev = _events(one_chip, jnp.float32, jnp.uint32, jnp.int32, jnp.bool_)
+    assert "tpu_custom_call" in _compile(topk_window_pallas, *state, *ev)
+
+
+@pytest.mark.parametrize("op", ["sum", "max"])
+def test_segment_reduce_compiles(one_chip, op):
+    from repro.kernels.segment_reduce import segment_reduce_pallas
+
+    n_seg = W * 2048  # above SPARSE_KEY_THRESHOLD keys per window
+    fn = lambda v, g, m: segment_reduce_pallas(v, g, m, n_seg, op=op)
+    args = _events(one_chip, jnp.float32, jnp.int32, jnp.bool_)
+    assert "tpu_custom_call" in _compile(fn, *args)
+
+
+def test_q4_delta_sync_pipeline_compiles_on_four_chips(topo, monkeypatch):
+    """The dataplane's default path for a Reduce-lattice query: delta sync
+    joins all-gathered deltas with the Pallas gated merge inside shard_map."""
+    from repro.kernels import ops
+    from repro.launch.stream import build_pipeline
+    from repro.streaming.events import EventBatch
+    from repro.streaming.queries import make_q4
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    jax.clear_caches()  # no CPU-dispatch trace of the kernel wrappers reused
+    n_dev, nb, epb = 4, 8, B
+    mesh = Mesh(np.array(topo.devices[:n_dev]), ("data",),
+                axis_types=(jax.sharding.AxisType.Auto,))
+    shard = NamedSharding(mesh, P("data"))
+    dtypes = dict(ts=jnp.int32, kind=jnp.int32, auction=jnp.uint32,
+                  price=jnp.float32, category=jnp.int32, bidder=jnp.uint32,
+                  valid=jnp.bool_)
+    log = EventBatch(**{
+        f: jax.ShapeDtypeStruct((n_dev, nb, epb), dt, sharding=shard)
+        for f, dt in dtypes.items()
+    })
+    query = make_q4(n_dev, window_len=10_000, num_slots=W)
+    pipe = build_pipeline(query, mesh, sync_every=4, n_windows=4)
+    compiled = pipe.lower(log).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the gated merge kernel is in
+    assert "all-gather" in text  # and the deltas cross chips
+    jax.clear_caches()

@@ -7,7 +7,7 @@ or duplicated deliveries.  Incomplete windows read as not-ok (None).
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import wcrdt as W
 from repro.core import wgcounter, wmaxreg, wtopk

@@ -10,7 +10,7 @@
 """
 import jax.numpy as jnp
 import numpy as np
-from _prop import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import wcrdt as W
 from repro.core import wgcounter
