@@ -1,0 +1,12 @@
+"""Keyed shuffle: device milliseconds per traced call of the ops under the
+program's ``shuffle`` scope (batch selection, routing matrix and the three
+``all_to_all``s), averaged over the cell's chips (``chipbench/scopes.py``).
+Nothing where the program names no such layer."""
+from chipbench import scopes
+
+
+def read(ctx):
+    got = scopes.read(ctx)
+    if got is None or not any(scopes.layer_of(p) == "shuffle" for p in got.ms):
+        return None
+    return got.layer_ms("shuffle")

@@ -1,0 +1,12 @@
+"""Sync: device milliseconds per traced call of the ops under the program's
+``sync`` scope (delta extract, exchange and merge; the keyed watermark
+``pmax``), averaged over the cell's chips (``chipbench/scopes.py``). Nothing
+where the program names no such layer."""
+from chipbench import scopes
+
+
+def read(ctx):
+    got = scopes.read(ctx)
+    if got is None or not any(scopes.layer_of(p) == "sync" for p in got.ms):
+        return None
+    return got.layer_ms("sync")

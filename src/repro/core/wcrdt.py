@@ -209,42 +209,46 @@ def insert(
         inputs = {k: _expand_payload(v, B, K) for k, v in inputs.items()}
     slot = wid % W
 
-    # Newest incoming window id per slot (masked lanes contribute NO_WID).
-    inc_wid = jnp.where(mask, wid, NO_WID)
-    seg_max = jax.ops.segment_max(
-        inc_wid, slot, num_segments=W, indices_are_sorted=False
-    )
-    seg_max = jnp.maximum(seg_max, NO_WID)  # empty segments -> -inf -> clamp
-    new_slot_wid = jnp.maximum(state.slot_wid, seg_max)
+    # Sub-scopes of the dataplane's fold layer (docs/observability.md §7):
+    # slot tenancy, ring-slot reset, and the scatter of the events.
+    with jax.named_scope("tenancy"):
+        # Newest incoming window id per slot (masked lanes contribute NO_WID).
+        inc_wid = jnp.where(mask, wid, NO_WID)
+        seg_max = jax.ops.segment_max(
+            inc_wid, slot, num_segments=W, indices_are_sorted=False
+        )
+        seg_max = jnp.maximum(seg_max, NO_WID)  # empty segments -> -inf -> clamp
+        new_slot_wid = jnp.maximum(state.slot_wid, seg_max)
 
-    # Reset slots whose tenant window advances.
-    advancing = new_slot_wid > state.slot_wid
-    # eviction-safety diagnostic: old tenant not yet complete?
-    gwm_wid = spec.assigner.first_dirty_wid(global_watermark(spec, state))
-    evict_bad = advancing & (state.slot_wid >= 0) & (state.slot_wid >= gwm_wid)
-    zeros = spec.zero_windows()
+        # Reset slots whose tenant window advances.
+        advancing = new_slot_wid > state.slot_wid
+        # eviction-safety diagnostic: old tenant not yet complete?
+        gwm_wid = spec.assigner.first_dirty_wid(global_watermark(spec, state))
+        evict_bad = advancing & (state.slot_wid >= 0) & (state.slot_wid >= gwm_wid)
 
     def reset(leaf, zleaf):
         extra = (1,) * (leaf.ndim - 1)
         adv = advancing.reshape((-1, *extra))
         return jnp.where(adv, zleaf, leaf)
 
-    windows = jax.tree.map(reset, state.windows, zeros)
+    with jax.named_scope("reset"):
+        windows = jax.tree.map(reset, state.windows, spec.zero_windows())
 
-    # Valid events: belong to the (new) tenant window of their slot.
-    stale = mask & (wid < new_slot_wid[slot])
-    valid = mask & ~stale
-    n_ring = jnp.sum(stale).astype(jnp.int32)
+    with jax.named_scope("scatter"):
+        # Valid events: belong to the (new) tenant window of their slot.
+        stale = mask & (wid < new_slot_wid[slot])
+        valid = mask & ~stale
+        n_ring = jnp.sum(stale).astype(jnp.int32)
 
-    if spec.max_active_windows is not None:
-        span = spec.max_active_windows
-        lo = jnp.min(jnp.where(valid, wid, jnp.int32(2**31 - 1)))
-        over = valid & (wid >= lo + span)
-        valid = valid & ~over
-        n_ring = n_ring + jnp.sum(over).astype(jnp.int32)
-        windows = spec.fold(windows, slot, valid, lo=lo, **inputs)
-    else:
-        windows = spec.fold(windows, slot, valid, **inputs)
+        if spec.max_active_windows is not None:
+            span = spec.max_active_windows
+            lo = jnp.min(jnp.where(valid, wid, jnp.int32(2**31 - 1)))
+            over = valid & (wid >= lo + span)
+            valid = valid & ~over
+            n_ring = n_ring + jnp.sum(over).astype(jnp.int32)
+            windows = spec.fold(windows, slot, valid, lo=lo, **inputs)
+        else:
+            windows = spec.fold(windows, slot, valid, **inputs)
 
     errors = state.errors
     errors = errors.at[ERR_LATE].add(n_late)
@@ -490,13 +494,17 @@ def delta_axis_join(
     (what a real transport would put on the network instead of the full
     ring; measured by benchmarks/throughput.py).
     """
-    delta = delta_since(spec, state, baseline_folded, baseline_progress)
-    shipped = delta_nbytes(delta)
-    gathered = jax.tree.map(lambda x: lax.all_gather(x, axis_name), delta)
-    merged = merge_delta_stack(
-        spec, gathered, use_pallas=use_pallas, interpret=interpret
-    )
-    return _merge_wstate(state, merged), shipped
+    # sub-scopes of the dataplane's sync layer (docs/observability.md §7)
+    with jax.named_scope("extract"):
+        delta = delta_since(spec, state, baseline_folded, baseline_progress)
+        shipped = delta_nbytes(delta)
+    with jax.named_scope("exchange"):
+        gathered = jax.tree.map(lambda x: lax.all_gather(x, axis_name), delta)
+    with jax.named_scope("merge"):
+        merged = merge_delta_stack(
+            spec, gathered, use_pallas=use_pallas, interpret=interpret
+        )
+        return _merge_wstate(state, merged), shipped
 
 
 

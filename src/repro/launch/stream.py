@@ -37,7 +37,7 @@ from repro.core import wcrdt as W
 from repro.core.window import as_assigner
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_data_mesh
-from repro.obs.timing import WallTimer
+from repro.obs.timing import DATAPLANE_LAYERS, WallTimer
 from repro.streaming.events import KIND_BID, EventBatch
 from repro.streaming.generator import NexmarkConfig, batch_watermark, generate_log
 from repro.streaming.queries import (
@@ -49,14 +49,19 @@ from repro.streaming.queries import (
     make_q7,
 )
 
-# every query the benchmarks import is runnable on the dataplane, including
-# the shared-state-free q0 (sync rounds no-op) and the sliding-window q5
+# the layer scopes every device op of the dataplane runs under
+# (docs/observability.md §7)
+SHUFFLE, FOLD, SYNC, READ = DATAPLANE_LAYERS
+
+
 def _vary(tree):
     """Mark freshly built (device-invariant) replica state as varying over
     ``data`` — what shard_map's vma check requires of a per-device carry."""
     return jax.tree.map(lambda x: lax.pcast(x, ("data",), to="varying"), tree)
 
 
+# every query the benchmarks import is runnable on the dataplane, including
+# the shared-state-free q0 (sync rounds no-op) and the sliding-window q5
 MAKERS = {
     "q0": make_q0,
     "q1_ratio": make_q1_ratio,
@@ -94,7 +99,8 @@ def build_pipeline(
         def fold_one(carry, batch):
             # batch_idx advances the folded frontier — what delta_since diffs
             shared, local, idx = carry
-            shared, local = query.fold(shared, local, batch, p, batch_idx=idx)
+            with jax.named_scope(FOLD):
+                shared, local = query.fold(shared, local, batch, p, batch_idx=idx)
             return (shared, local, idx + 1), None
 
         def sync_chunk(carry, chunk):
@@ -104,15 +110,16 @@ def build_pipeline(
                 fold_one, (shared, local, idx), chunk
             )
             synced, new_base = [], []
-            for spec, st, (bf, bp) in zip(query.shared_specs, shared, baselines):
-                if delta_sync:
-                    st, shipped = W.delta_axis_join(spec, st, bf, bp, "data")
-                else:
-                    st = W.axis_join(spec, st, "data")
-                    shipped = jnp.float32(W.state_nbytes(st))
-                sync_bytes = sync_bytes + shipped
-                synced.append(st)
-                new_base.append(W.baseline_of(st))
+            with jax.named_scope(SYNC):
+                for spec, st, (bf, bp) in zip(query.shared_specs, shared, baselines):
+                    if delta_sync:
+                        st, shipped = W.delta_axis_join(spec, st, bf, bp, "data")
+                    else:
+                        st = W.axis_join(spec, st, "data")
+                        shipped = jnp.float32(W.state_nbytes(st))
+                    sync_bytes = sync_bytes + shipped
+                    synced.append(st)
+                    new_base.append(W.baseline_of(st))
             return (tuple(synced), local, idx, tuple(new_base), sync_bytes), None
 
         log0 = jax.tree.map(lambda x: x[0], log)  # strip device-local lead dim
@@ -133,7 +140,8 @@ def build_pipeline(
             v, ok = query.read(shared, local, w)
             return jnp.where(ok, 1.0, 0.0), v
 
-        oks, vals = jax.vmap(read)(first_window + jnp.arange(n_windows))
+        with jax.named_scope(READ):
+            oks, vals = jax.vmap(read)(first_window + jnp.arange(n_windows))
         return oks[None], vals[None], sync_bytes[None]
 
     log_specs = jax.tree.map(lambda _: P("data"), EventBatch(*([0] * 7)))
@@ -160,7 +168,7 @@ def default_fold_schedule(num_shards: int, num_batches: int) -> np.ndarray:
 def build_keyed_pipeline(
     mesh, shards: W.KeyShards, *, window_len: int = 1000,
     num_slots: int = 16, hop: int | None = None, sync_every: int = 4,
-    n_windows: int = 8, first_window: int = 0, provenance: bool = False,
+    n_windows: int = 8, first_window: int = 0,
 ):
     """Hash-sharded keyed dataplane (docs/protocol.md §6): per-auction bid
     counts + cross-shard hot-item reads over a key domain too large for any
@@ -189,16 +197,6 @@ def build_keyed_pipeline(
     slot deltas to reconcile); both modeled byte counters come back as
     outputs.  Final read: :func:`W.shard_topk_read` per window — one
     ``[S]``-candidate gather, never the full key range.
-
-    With ``provenance=True`` the jitted fn returns a fifth output: each
-    device's i32 ``[S]`` **ingest frontier** — the max event timestamp among
-    the keyed lanes it folded from each source device (``-2^31`` where a
-    source never routed it a bid).  This is the dataplane analog of the sync
-    plane's progress lattice: the host can tell which *source's* routed
-    lanes gate an owner's window close, the per-lane provenance the
-    critical-path analyzer reconstructs for the coordination harness
-    (docs/observability.md §5).  Default stays the 4-output signature with
-    zero added work.
     """
     S = shards.num_shards
     assigner = as_assigner(window_len, hop if hop else window_len // 2)
@@ -217,48 +215,45 @@ def build_keyed_pipeline(
         )
 
         def fold_step(carry, sched_col):
-            state, shuffle_bytes, prov = carry
-            batch = jax.tree.map(lambda x: x[sched_col[me]], log0)
-            is_bid = batch.valid & (batch.kind == KIND_BID)
-            owner = shards.shard_of(batch.auction)
-            local = shards.local_of(batch.auction)
-            # routing matrix: row s = my lanes owned by device s
-            m_sb = is_bid[None, :] & (owner[None, :] == rows)  # [S, B]
-            r_ts = a2a(jnp.broadcast_to(batch.ts[None, :], (S, B)))
-            r_loc = a2a(jnp.broadcast_to(local[None, :], (S, B)))
-            r_mask = a2a(m_sb)
-            # wire model: off-device lanes ship (ts, local) = 8 bytes each
-            sent = m_sb & (rows != me)
-            shuffle_bytes = shuffle_bytes + jnp.sum(sent) * jnp.float32(8.0)
-            # after the exchange, row r holds lanes from source device r,
-            # folded at r's scheduled batch index (sched is replicated)
-            src = jnp.broadcast_to(rows, (S, B)).reshape(-1)
-            bi = jnp.broadcast_to(sched_col[:, None], (S, B)).reshape(-1)
-            state = W.insert(
-                spec, state, src, r_ts.reshape(-1), r_mask.reshape(-1),
-                batch_idx=bi, amounts=jnp.ones((S * B,), jnp.float32),
-                keys=r_loc.reshape(-1),
-            )
-            if provenance:
-                # ingest frontier: max event ts among the lanes row r (source
-                # device r) routed to me this step — flag-static, so the
-                # default build traces no extra ops
-                lane_ts = jnp.where(r_mask, r_ts, jnp.int32(-(2**31)))
-                prov = jnp.maximum(prov, lane_ts.max(axis=1))
-            state = W.increment_watermark(spec, state, me, batch_watermark(batch))
-            return (state, shuffle_bytes, prov), None
+            state, shuffle_bytes = carry
+            with jax.named_scope(SHUFFLE):
+                batch = jax.tree.map(lambda x: x[sched_col[me]], log0)
+                is_bid = batch.valid & (batch.kind == KIND_BID)
+                owner = shards.shard_of(batch.auction)
+                local = shards.local_of(batch.auction)
+                # routing matrix: row s = my lanes owned by device s
+                m_sb = is_bid[None, :] & (owner[None, :] == rows)  # [S, B]
+                r_ts = a2a(jnp.broadcast_to(batch.ts[None, :], (S, B)))
+                r_loc = a2a(jnp.broadcast_to(local[None, :], (S, B)))
+                r_mask = a2a(m_sb)
+                # wire model: off-device lanes ship (ts, local) = 8 bytes each
+                sent = m_sb & (rows != me)
+                shuffle_bytes = shuffle_bytes + jnp.sum(sent) * jnp.float32(8.0)
+            with jax.named_scope(FOLD):
+                # after the exchange, row r holds lanes from source device r,
+                # folded at r's scheduled batch index (sched is replicated)
+                src = jnp.broadcast_to(rows, (S, B)).reshape(-1)
+                bi = jnp.broadcast_to(sched_col[:, None], (S, B)).reshape(-1)
+                state = W.insert(
+                    spec, state, src, r_ts.reshape(-1), r_mask.reshape(-1),
+                    batch_idx=bi, amounts=jnp.ones((S * B,), jnp.float32),
+                    keys=r_loc.reshape(-1),
+                )
+                state = W.increment_watermark(spec, state, me, batch_watermark(batch))
+            return (state, shuffle_bytes), None
 
         def sync_round(carry, round_in):
             chunk, wm_on = round_in
-            state, shuffle_bytes, sync_bytes, prov = carry
-            (state, shuffle_bytes, prov), _ = jax.lax.scan(
-                fold_step, (state, shuffle_bytes, prov), chunk
+            state, shuffle_bytes, sync_bytes = carry
+            (state, shuffle_bytes), _ = jax.lax.scan(
+                fold_step, (state, shuffle_bytes), chunk
             )
-            merged = jnp.where(wm_on, jax.lax.pmax(state.progress, "data"),
-                               state.progress)
-            state = dataclasses.replace(state, progress=merged)
-            sync_bytes = sync_bytes + jnp.where(wm_on, wm_bytes, 0.0)
-            return (state, shuffle_bytes, sync_bytes, prov), None
+            with jax.named_scope(SYNC):
+                merged = jnp.where(wm_on, jax.lax.pmax(state.progress, "data"),
+                                   state.progress)
+                state = dataclasses.replace(state, progress=merged)
+                sync_bytes = sync_bytes + jnp.where(wm_on, wm_bytes, 0.0)
+            return (state, shuffle_bytes, sync_bytes), None
 
         n_steps = sched.shape[1]
         n_rounds = n_steps // sync_every
@@ -268,9 +263,8 @@ def build_keyed_pipeline(
             .astype(jnp.int32)
         )
         zero = _vary(jnp.float32(0.0))
-        prov0 = _vary(jnp.full((S,), -(2**31), jnp.int32))
-        (state, shuffle_bytes, sync_bytes, prov), _ = jax.lax.scan(
-            sync_round, (state, zero, zero, prov0), (chunks, wm_sync[:n_rounds])
+        (state, shuffle_bytes, sync_bytes), _ = jax.lax.scan(
+            sync_round, (state, zero, zero), (chunks, wm_sync[:n_rounds])
         )
 
         def read(w):
@@ -280,20 +274,17 @@ def build_keyed_pipeline(
             val = jnp.stack([cnt[0], key[0].astype(jnp.float32)])
             return jnp.where(ok, 1.0, 0.0), val
 
-        oks, vals = jax.vmap(read)(first_window + jnp.arange(n_windows))
-        out = (oks[None], vals[None], shuffle_bytes[None], sync_bytes[None])
-        if provenance:
-            out += (prov[None],)
-        return out
+        with jax.named_scope(READ):
+            oks, vals = jax.vmap(read)(first_window + jnp.arange(n_windows))
+        return oks[None], vals[None], shuffle_bytes[None], sync_bytes[None]
 
-    n_out = 5 if provenance else 4
     log_specs = jax.tree.map(lambda _: P("data"), EventBatch(*([0] * 7)))
     return jax.jit(
         jax.shard_map(
             node_fn,
             mesh=mesh,
             in_specs=(log_specs, P("data"), P(), P()),
-            out_specs=tuple(P("data") for _ in range(n_out)),
+            out_specs=(P("data"), P("data"), P("data"), P("data")),
         )
     )
 

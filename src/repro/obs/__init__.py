@@ -18,7 +18,9 @@ run being observed:
   merge → emit) and attributes its length to phases, per topology;
 * **online monitor** (obs/monitor.py) — the auditor's invariants plus
   operational health alerts, incrementally in bounded memory over the live
-  telemetry stream.
+  telemetry stream;
+* **dataplane scopes** (obs/timing.py ``DATAPLANE_LAYERS``) — the layer
+  names the jitted dataplane gives its device ops (docs/observability.md §7).
 
 Determinism is the contract: a same-seed run exports a byte-identical
 trace, which is what makes the trace auditable at all.
@@ -43,7 +45,7 @@ from repro.obs.records import (
 )
 from repro.obs.registry import Counter, Gauge, Histogram, MetricsRegistry, summary
 from repro.obs.telemetry import Telemetry
-from repro.obs.timing import SimTimer, WallTimer
+from repro.obs.timing import DATAPLANE_LAYERS, SimTimer, WallTimer
 
 __all__ = [
     "AuditReport",
@@ -70,6 +72,7 @@ __all__ = [
     "MetricsRegistry",
     "summary",
     "Telemetry",
+    "DATAPLANE_LAYERS",
     "SimTimer",
     "WallTimer",
 ]

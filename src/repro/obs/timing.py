@@ -8,10 +8,19 @@ same-seed bit-identical guarantee).  Wall-clock timing exists only at the
 edges — the real jitted dataplane in launch/stream.py, benchmark drivers —
 and goes through :class:`WallTimer`, whose ``domain`` tag follows the
 measurement into metric names and benchmark rows.
+
+The third clock is the device's.  The jitted dataplane names its layers with
+``jax.named_scope``s drawn from :data:`DATAPLANE_LAYERS`; they travel as op
+metadata of the compiled program, so a profiler trace puts each device op
+down to a layer on the device's own timeline (docs/observability.md §7).
 """
 from __future__ import annotations
 
 import time
+
+# The dataplane's layers, outermost scope names of launch/stream.py.  Scopes
+# nested inside one of them refine it and never count as layers of their own.
+DATAPLANE_LAYERS = ("shuffle", "fold", "sync", "read")
 
 
 class WallTimer:

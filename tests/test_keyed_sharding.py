@@ -141,45 +141,6 @@ print("KEYED_2DEV_OK")
     _run_child(script, "KEYED_2DEV_OK")
 
 
-def test_keyed_provenance_frontier_2dev():
-    """``provenance=True`` adds a fifth output — each owner's per-source
-    ingest-timestamp frontier over the keyed lanes it folded — without
-    changing the default 4-output signature or any default output: the
-    frontier must equal the host-side oracle (max bid ts per (owner,
-    source) routed pair), and the provenance build's windows/values must be
-    byte-identical to the default build's."""
-    script = _CHILD_COMMON % dict(S=2, C=10_000, nb=8, epb=256) + """
-from repro.streaming.events import KIND_BID
-
-with mesh:
-    pipe_p = build_keyed_pipeline(mesh, shards, window_len=wl, num_slots=16,
-                                  sync_every=4, n_windows=n_win,
-                                  first_window=first, provenance=True)
-    out = pipe_p(log, table, jnp.asarray(base), jnp.ones(nb // 4, bool))
-assert len(out) == 5  # default build returned 4 (run() unpacks 4-tuples)
-oks4, vals4, shuf4, sync4, prov = (np.asarray(x) for x in out)
-np.testing.assert_array_equal(oks4, oks0)
-np.testing.assert_array_equal(vals4, vals0)
-np.testing.assert_array_equal(shuf4, shuf0)
-
-ts, valid = np.asarray(log.ts), np.asarray(log.valid)
-bid = valid & (np.asarray(log.kind) == KIND_BID)
-auc = np.asarray(log.auction)
-own = np.asarray(shards.shard_of(jnp.asarray(auc.reshape(-1), jnp.uint32)))
-own = own.reshape(auc.shape)
-want = np.full((S, S), -(2**31), np.int64)
-for s in range(S):
-    for d in range(S):
-        m = bid[s] & (own[s] == d)
-        if m.any():
-            want[d, s] = ts[s][m].max()
-np.testing.assert_array_equal(prov.astype(np.int64), want)
-assert (want > 0).all()  # every routed pair actually saw bids
-print("KEYED_PROV_OK")
-"""
-    _run_child(script, "KEYED_PROV_OK")
-
-
 @pytest.mark.multidevice
 def test_keyed_dataplane_8dev_crash_and_partition():
     """8-way sharded q5 under chaos: a crash-replay fold schedule and a
