@@ -120,6 +120,11 @@ class WSpec:
     # ``ts // window_len`` bit-for-bit; Hopping(window_len, hop) maps each
     # event into window_len // hop overlapping windows.  None -> Tumbling.
     assigner: WindowAssigner | None = None
+    # Ring-slot reset: (windows, advancing bool[W]) -> windows with the
+    # advancing slots zeroed.  None -> a ``jnp.where`` against
+    # ``zero_windows()`` over each leaf's leading [W] axis; a spec whose
+    # leaves have no such axis (the flat keyed ring) brings its own.
+    reset: Callable[[Any, jax.Array], Any] | None = None
 
     def __post_init__(self):
         if self.assigner is None:
@@ -232,7 +237,10 @@ def insert(
         return jnp.where(adv, zleaf, leaf)
 
     with jax.named_scope("reset"):
-        windows = jax.tree.map(reset, state.windows, spec.zero_windows())
+        if spec.reset is None:
+            windows = jax.tree.map(reset, state.windows, spec.zero_windows())
+        else:
+            windows = spec.reset(state.windows, advancing)
 
     with jax.named_scope("scatter"):
         # Valid events: belong to the (new) tenant window of their slot.
@@ -687,6 +695,40 @@ class KeyShards:
         return table
 
 
+def _fold_flat(width, width_p, windows, slot_ids, mask, amounts, keys):
+    """Scatter-add ``amounts`` at ``slot * width_p + key``.  Masked lanes and
+    keys outside ``[0, width)`` are sent one past the ring and dropped, so
+    the padding never takes a count."""
+    n = windows.shape[0]
+    keys = keys.astype(jnp.int32)
+    keep = mask & (keys >= 0) & (keys < width)
+    idx = jnp.where(keep, slot_ids * jnp.int32(width_p) + keys, jnp.int32(n))
+    return windows.at[idx].add(amounts.astype(windows.dtype), mode="drop")
+
+
+def _read_flat(width, width_p, windows, slot):
+    """Slot ``slot``'s ``[width]`` counts.  The slice takes the whole
+    tile-aligned row and drops the padding after it: a slice of ``width``
+    alone ends inside a tile, which the chip's compiler relayouts row by row."""
+    return lax.dynamic_slice(windows, (slot * width_p,), (width_p,))[:width]
+
+
+def _reset_flat(width_p, windows, advancing):
+    """Zero the advancing slots' rows in place, one row write per advancing
+    slot; the other rows are neither read nor written."""
+    zeros = jnp.zeros((width_p,), windows.dtype)
+
+    def row(s, w):
+        return lax.cond(
+            advancing[s],
+            lambda w: lax.dynamic_update_slice(w, zeros, (s * width_p,)),
+            lambda w: w,
+            w,
+        )
+
+    return lax.fori_loop(0, advancing.shape[0], row, windows)
+
+
 def wgcounter_sharded(
     window_len: int, num_slots: int, num_partitions: int, shards: KeyShards,
     dtype=jnp.float32, assigner: WindowAssigner | None = None,
@@ -694,28 +736,45 @@ def wgcounter_sharded(
     """Keyed grow-only counter over ONE shard's key range
     (docs/protocol.md §6).
 
-    State is ``[W, 1, width]``: the key axis holds only this shard's
-    ``ceil(C/S)`` locals, and the actor axis collapses to 1 because folds are
-    owner-exclusive — every event for a key is routed to its single owner, so
-    no per-actor slots are needed for merge monotonicity (replay idempotence
-    still comes from the ``folded`` frontier, which keeps all
-    ``num_partitions`` source entries, as does ``progress``).  The generic
-    WState machinery (``delta_since``/``merge``/``window_value``) operates on
-    this per-key-range state unchanged — a delta ships only the owner's dirty
-    slots of its own range.  Fold inputs: ``amounts`` per lane plus ``keys``
-    = LOCAL indices (route with :meth:`KeyShards.local_of` first).
+    The windows are one flat ``[num_slots * width_p]`` leaf: slot ``s``'s
+    counts for this shard's ``width = ceil(C/S)`` locals are the row
+    ``[s * width_p, s * width_p + width)``, where ``width_p`` is ``width``
+    rounded up to a multiple of 1024.  Rows start on whole TPU tiles, so the
+    fold scatters into the ring where it lies (the chip's compiler copies a
+    ``[W, 1, width]`` ring to a flat buffer and back around every scatter),
+    and the spec's ``reset`` zeroes only the advancing slots' rows.
+    The padding past ``width`` in each row stays zero.  There is no actor
+    axis: folds are owner-exclusive, every event for a key routed to its
+    single owner (replay idempotence still comes from the ``folded``
+    frontier, which keeps all ``num_partitions`` source entries, as does
+    ``progress``).
+
+    ``insert``, ``increment_watermark``, ``window_value`` and
+    :func:`shard_topk_read` serve this state; ``window_value`` returns the
+    slot's ``[width]`` counts.  The slot-wise join machinery (``merge``,
+    ``delta_since`` and the syncs built on them) expects a CRDT pytree with
+    a leading ``[W]`` axis on every leaf and raises on this state; none of
+    it is needed, since owners never reconcile slots and the keyed
+    dataplane syncs only ``progress``.  Fold inputs: ``amounts`` per lane
+    plus ``keys`` = LOCAL indices (route with :meth:`KeyShards.local_of`
+    first).
     """
     width = shards.width
+    width_p = -(-width // 1024) * 1024  # rows start on whole 1-D tiles
+    if num_slots * width_p >= 2**31:
+        raise ValueError(
+            f"num_slots * width_p = {num_slots * width_p} overflows i32 ring "
+            "indices; shard the key range over more devices"
+        )
     return WSpec(
         window_len=window_len,
         assigner=assigner,
         num_slots=num_slots,
         num_partitions=num_partitions,
-        zero_windows=partial(
-            crdts.GCounter.zero_windows, num_slots, 1, (width,), dtype
-        ),
-        fold=lambda w, s, m, amounts, keys: w.fold_windows(s, m, 0, amounts, keys),
-        read=lambda w, slot: w.window_value(slot),
+        zero_windows=partial(jnp.zeros, (num_slots * width_p,), dtype),
+        fold=partial(_fold_flat, width, width_p),
+        read=partial(_read_flat, width, width_p),
+        reset=partial(_reset_flat, width_p),
     )
 
 

@@ -78,6 +78,107 @@ def test_shard_and_merge_law():
         np.testing.assert_array_equal(recon, np.asarray(dv))
 
 
+@pytest.mark.parametrize(
+    "C,S,slots,nb,hop",
+    [
+        (1000, 4, 8, 6, 50),  # hopping, K = 2; the ring never wraps
+        (1000, 4, 4, 14, 50),  # K = 2, more batches than slots: rows reset mid-run
+        (5000, 3, 3, 10, 100),  # tumbling; width 1667 padded to 2048
+        (2048, 1, 4, 10, 50),  # one shard, width exactly two tiles
+    ],
+    ids=["hop-k2", "hop-k2-ring-wraps", "tumbling-padded-wraps", "one-shard-aligned"],
+)
+def test_flat_ring_matches_dense_counter(C, S, slots, nb, hop):
+    """The flat keyed ring against the dense keyed counter: after every batch,
+    each window the ring reads as complete holds, key for key, exactly the
+    dense counter's counts; every window that completes is read so at least
+    once; masked lanes count nowhere; row padding stays zero."""
+    wl = 100
+    assigner = as_assigner(wl, hop)
+    sh = W.KeyShards(C, S)
+    width_p = -(-sh.width // 1024) * 1024
+    n_wids = nb * 50 // hop + 2
+    dense = W.wgcounter(wl, n_wids + 2, 1, key_shape=(C,), assigner=assigner)
+    flat = W.wgcounter_sharded(wl, slots, 1, sh, assigner=assigner)
+    insert = jax.jit(W.insert, static_argnums=0)
+    value = jax.jit(W.window_value, static_argnums=0)
+    table = sh.key_table()
+
+    rng = np.random.default_rng(C + slots)
+    dstate, fstates = dense.zero(), [flat.zero() for _ in range(S)]
+    checked = set()
+    B = 128
+    for b in range(nb):
+        ts = jnp.sort(jnp.asarray(rng.integers(b * 50, (b + 1) * 50, B), jnp.int32))
+        keys = jnp.asarray(rng.zipf(1.3, B) % C, jnp.uint32)
+        mask = rng.random(B) < 0.85
+        mask[0] = False  # at least one masked-out lane per batch
+        mask = jnp.asarray(mask)
+        amounts = jnp.ones((B,), jnp.float32)
+        wm = int(ts.max())
+        dstate = insert(dense, dstate, 0, ts, mask, batch_idx=b, actor=0,
+                        amounts=amounts, keys=keys.astype(jnp.int32))
+        dstate = W.increment_watermark(dense, dstate, 0, wm)
+        own, loc = sh.shard_of(keys), sh.local_of(keys)
+        for s in range(S):
+            fstates[s] = insert(flat, fstates[s], 0, ts, mask & (own == s),
+                                batch_idx=b, amounts=amounts, keys=loc)
+            fstates[s] = W.increment_watermark(flat, fstates[s], 0, wm)
+        for wid in range(n_wids):
+            dv, dok = value(dense, dstate, wid)
+            reads = [value(flat, st, wid) for st in fstates]
+            if not all(bool(ok) for _, ok in reads):
+                continue
+            assert bool(dok), wid
+            recon = np.zeros(C, np.float32)
+            for s, (sv, _) in enumerate(reads):
+                assert sv.shape == (sh.width,)
+                n = sh.num_local(s)
+                recon[table[s, :n]] = np.asarray(sv)[:n]
+            np.testing.assert_array_equal(recon, np.asarray(dv))
+            checked.add(wid)
+
+    closed = [w for w in range(n_wids) if bool(value(dense, dstate, w)[1])]
+    assert len(closed) >= n_wids - 4 and set(closed) <= checked, (closed, checked)
+    for st in fstates:
+        np.testing.assert_array_equal(np.asarray(st.errors), 0)
+        rows = np.asarray(st.windows).reshape(slots, width_p)
+        np.testing.assert_array_equal(rows[:, sh.width:], 0)
+    assert int(np.asarray(dstate.errors).sum()) == 0
+
+
+def test_flat_ring_layout_and_refusals():
+    """What ``wgcounter_sharded``'s docstring states: one flat
+    ``[W * width_p]`` leaf with tile-aligned rows, a reset that zeroes only
+    the advancing rows, a ``[width]`` read, an i32 guard, and the slot-wise
+    join machinery refusing the state."""
+    sh = W.KeyShards(5000, 3)  # width 1667
+    spec = W.wgcounter_sharded(100, 4, 2, sh, assigner=as_assigner(100, 50))
+    state = spec.zero()
+    assert state.windows.shape == (4 * 2048,)
+
+    ring = jnp.arange(4 * 2048, dtype=jnp.float32) + 1
+    out = np.asarray(spec.reset(ring, jnp.array([False, True, False, True])))
+    rows, before = out.reshape(4, 2048), np.asarray(ring).reshape(4, 2048)
+    np.testing.assert_array_equal(rows[[1, 3]], 0)
+    np.testing.assert_array_equal(rows[[0, 2]], before[[0, 2]])
+
+    ts = jnp.array([10, 20, 60], jnp.int32)
+    keys = jnp.array([0, 1666, 5], jnp.int32)
+    state = W.insert(spec, state, 0, ts, jnp.ones(3, bool), batch_idx=0,
+                     amounts=jnp.ones(3, jnp.float32), keys=keys)
+    v, _ = W.window_value(spec, state, 0)
+    assert v.shape == (1667,)
+    np.testing.assert_array_equal(np.flatnonzero(np.asarray(v)), [0, 5, 1666])
+
+    with pytest.raises((KeyError, TypeError, ValueError)):
+        W.merge(spec, state, state)
+    with pytest.raises((KeyError, TypeError, ValueError)):
+        W.delta_since(spec, state, *W.zero_baseline(spec))
+    with pytest.raises(ValueError, match="overflows i32"):
+        W.wgcounter_sharded(100, 16, 1, W.KeyShards(2**27, 1))
+
+
 def _run_child(script: str, sentinel: str, timeout: int = 600):
     src = Path(__file__).resolve().parents[1] / "src"
     env = dict(os.environ, PYTHONPATH=str(src))

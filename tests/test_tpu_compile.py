@@ -47,6 +47,19 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _event_log(sharding, shape):
+    """EventBatch of ``shape`` operands, one per field, on ``sharding``."""
+    from repro.streaming.events import EventBatch
+
+    dtypes = dict(ts=jnp.int32, kind=jnp.int32, auction=jnp.uint32,
+                  price=jnp.float32, category=jnp.int32, bidder=jnp.uint32,
+                  valid=jnp.bool_)
+    return EventBatch(**{
+        f: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+        for f, dt in dtypes.items()
+    })
+
+
 def test_gated_delta_merge_compiles(one_chip):
     from repro.kernels.ops import gated_delta_merge
 
@@ -106,7 +119,6 @@ def test_q4_delta_sync_pipeline_compiles_on_four_chips(topo, monkeypatch):
     joins all-gathered deltas with the Pallas gated merge inside shard_map."""
     from repro.kernels import ops
     from repro.launch.stream import build_pipeline
-    from repro.streaming.events import EventBatch
     from repro.streaming.queries import make_q4
 
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
@@ -114,14 +126,7 @@ def test_q4_delta_sync_pipeline_compiles_on_four_chips(topo, monkeypatch):
     n_dev, nb, epb = 4, 8, B
     mesh = Mesh(np.array(topo.devices[:n_dev]), ("data",),
                 axis_types=(jax.sharding.AxisType.Auto,))
-    shard = NamedSharding(mesh, P("data"))
-    dtypes = dict(ts=jnp.int32, kind=jnp.int32, auction=jnp.uint32,
-                  price=jnp.float32, category=jnp.int32, bidder=jnp.uint32,
-                  valid=jnp.bool_)
-    log = EventBatch(**{
-        f: jax.ShapeDtypeStruct((n_dev, nb, epb), dt, sharding=shard)
-        for f, dt in dtypes.items()
-    })
+    log = _event_log(NamedSharding(mesh, P("data")), (n_dev, nb, epb))
     query = make_q4(n_dev, window_len=10_000, num_slots=W)
     pipe = build_pipeline(query, mesh, sync_every=4, n_windows=4)
     compiled = pipe.lower(log).compile()
@@ -129,3 +134,45 @@ def test_q4_delta_sync_pipeline_compiles_on_four_chips(topo, monkeypatch):
     assert "tpu_custom_call" in text  # the gated merge kernel is in
     assert "all-gather" in text  # and the deltas cross chips
     jax.clear_caches()
+
+
+@pytest.mark.parametrize("n_windows", [11, 1])
+def test_keyed_q5_ring_stays_in_the_scatters_layout(topo, n_windows):
+    """The one-chip q5 cell's keyed dataplane at its real size: 2.5e7 keys, a
+    16-slot ring, 10 s windows sliding by 5 s, 8 x 65,536 events per call.
+    The ring is one flat tile-aligned ``f32[16 * width_p]`` buffer that the
+    scatter adds into where it lies: no ``[16, 1, 2.5e7]`` ring, no flat copy
+    of it, and no ring-sized dynamic-update-slice outside the slot reset.
+    With one window read, the program holds the ring and little else; with
+    the cell's 11, the read adds the zero ring and two ``[11, width_p]``
+    buffers (tiled to 16 rows), and still no buffer of the fold's."""
+    from repro.core.wcrdt import KeyShards
+    from repro.launch.stream import build_keyed_pipeline
+
+    nb, epb = 8, 65_536
+    shards = KeyShards(25_000_000, 1)
+    width_p = -(-shards.width // 1024) * 1024
+    ring = f"f32[{16 * width_p}]"
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",),
+                axis_types=(jax.sharding.AxisType.Auto,))
+    shard, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    log = _event_log(shard, (1, nb, epb))
+    pipe = build_keyed_pipeline(mesh, shards, window_len=10_000, num_slots=16,
+                                hop=5_000, sync_every=4, n_windows=n_windows)
+    compiled = pipe.lower(
+        log,
+        jax.ShapeDtypeStruct((1, shards.width), jnp.uint32, sharding=shard),
+        jax.ShapeDtypeStruct((1, nb), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((nb // 4,), jnp.bool_, sharding=rep),
+    ).compile()
+    text = compiled.as_text()
+
+    assert "16,1,25000000" not in text and "f32[400000000]" not in text
+    assert any(ring in l and " scatter(" in l for l in text.splitlines())
+    for line in text.splitlines():
+        rhs = line.partition(" = ")[2]
+        if rhs.startswith(ring) and " dynamic-update-slice(" in rhs:
+            assert "/fold/reset/" in rhs, line[:200]
+    ring_bytes = 16 * width_p * 4
+    held = ring_bytes * (1 if n_windows == 1 else 4) + 100e6
+    assert compiled.memory_analysis().temp_size_in_bytes <= held
