@@ -20,6 +20,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.core.lattice import (
@@ -33,6 +34,9 @@ from repro.core.lattice import (
 NEG_INF = jnp.float32(-jnp.inf)
 # float_to_ordered_u32(-inf): the TopK padding value in the sort-key domain
 _NEG_INF_KEY = 0x007FFFFF
+# the NaN whose ordered-u32 key is 0: TopK's pre-filter puts masked lanes here,
+# below every price, as lax.top_k orders floats by that key
+_BELOW_EVERY_PRICE = np.array(0xFFFFFFFF, np.uint32).view(np.float32)
 
 
 def _masked(vals: jax.Array, mask: jax.Array, fill) -> jax.Array:
@@ -352,13 +356,23 @@ class TopK:
         )
 
     def fold_windows(self, slot_ids, mask, vals, ids, lo=None, active: int = 8) -> "TopK":
-        """Per-window top-k fold of a batch.
+        """Per-window top-k fold of a batch: each slot's row joined with the
+        batch's masked lanes of that slot's window.
 
         Fast path (``lo`` given, from WSpec.max_active_windows): a partition-
         ordered batch spans only a few windows, so fold just ``active`` window
-        offsets starting at the batch's lowest window id — per offset, a
-        ``lax.top_k`` pre-reduction of the batch then a tiny 2k-sorted join.
-        This is the jnp analogue of the Pallas ``topk_window`` kernel.
+        offsets starting at the batch's lowest window id.  Per offset,
+        ``prefilter`` keeps the batch's k + 1 highest lanes with
+        ``lax.top_k``, which orders floats by the join's own key (their
+        ordered-u32 bits), masked lanes below every price; ``setjoin`` joins
+        the first k with the row in a 2k sort.  That equals the join with
+        every lane unless a lane left out can still reach the row's top k: a
+        tie between the k-th and (k+1)-th key, or a duplicate (price, id)
+        pair among the k that frees a place.  A batch with such an offset
+        takes the ``exact`` join of every lane for every offset, under one
+        ``lax.cond`` outside the vmap, so the common batch runs one branch.
+        Prices are not NaN.  This is the jnp analogue of the Pallas
+        ``topk_window`` kernel.
         Fallback: masked join vmapped over every ring slot.
         """
         W = self.vals.shape[0]
@@ -378,18 +392,46 @@ class TopK:
 
         wid_of_slot = lo + jnp.arange(active, dtype=jnp.int32)
         slots = wid_of_slot % W
+        rows_v, rows_i = self.vals[slots], self.ids[slots]
 
-        def per_off(w, slot):
-            m = mask & (slot_ids == slot) & (w >= 0)
-            bv = jnp.where(m, vals, -jnp.inf)
-            # pre-reduce the batch to its top-k, then a 2k set-join
-            tv, ti = lax.top_k(bv, k)
-            tids = jnp.where(tv > -jnp.inf, ids[ti], 0)
-            return _topk_join_sorted(self.vals[slot], self.ids[slot], tv, tids, k)
+        def lanes(w, slot):
+            return mask & (slot_ids == slot) & (w >= 0)
 
-        v, i = jax.vmap(per_off)(wid_of_slot, slots)
-        # offsets map to distinct slots (active <= W); scatter rows back
-        return TopK(self.vals.at[slots].set(v), self.ids.at[slots].set(i))
+        def prefilter(w, slot):
+            m, v, i = lanes(w, slot), vals, ids
+            if v.shape[0] <= k:  # top_k needs k + 1 lanes: pad with masked ones
+                pad = k + 1 - v.shape[0]
+                m, v, i = jnp.pad(m, (0, pad)), jnp.pad(v, (0, pad)), jnp.pad(i, (0, pad))
+            top, at = lax.top_k(jnp.where(m, v, _BELOW_EVERY_PRICE), k + 1)
+            key = float_to_ordered_u32(top)  # 0: a masked lane
+            cand_v = jnp.where(key > 0, top, -jnp.inf)
+            cand_i = jnp.where(key > 0, i[at], 0)
+            # every live lane is a candidate, or every lane left out lies
+            # strictly below k distinct candidates
+            upper = jnp.arange(k)[:, None] < jnp.arange(k)[None, :]
+            dup = jnp.any(upper & (key[:k, None] == key[None, :k])
+                          & (cand_i[:k, None] == cand_i[None, :k]))
+            ok = (key[k] == 0) | ((key[k - 1] > key[k]) & ~dup)
+            return cand_v[:k], cand_i[:k], ok
+
+        def exact():
+            with jax.named_scope("exact"):
+                def per_off(w, slot, sv, si):
+                    m = lanes(w, slot)
+                    return _topk_join_sorted(sv, si, jnp.where(m, vals, -jnp.inf),
+                                             jnp.where(m, ids, 0), k)
+
+                return jax.vmap(per_off)(wid_of_slot, slots, rows_v, rows_i)
+
+        with jax.named_scope("prefilter"):
+            cand_v, cand_i, ok = jax.vmap(prefilter)(wid_of_slot, slots)
+            unambiguous = jnp.all(ok)
+        with jax.named_scope("setjoin"):
+            fast = _topk_join_sorted(rows_v, rows_i, cand_v, cand_i, k)
+        v, i = lax.cond(unambiguous, lambda: fast, exact)
+        with jax.named_scope("setjoin"):
+            # offsets map to distinct slots (active <= W); scatter rows back
+            return TopK(self.vals.at[slots].set(v), self.ids.at[slots].set(i))
 
     def window_value(self, slot) -> tuple[jax.Array, jax.Array]:
         return self.vals[slot], self.ids[slot]

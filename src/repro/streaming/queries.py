@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import wcrdt as W
+from repro.core.lattice import float_to_ordered_u32, ordered_u32_to_float
 from repro.core.wcrdt import WSpec, WState
 from repro.core.window import WindowAssigner, as_assigner
 from repro.streaming.events import KIND_BID, EventBatch
@@ -244,9 +245,17 @@ def make_q7(
     def oracle(log: EventBatch, wid, partition=None):
         m = log.valid & (log.kind == KIND_BID) & assigner.contains(wid, log.ts)
         prices = jnp.where(m, log.price, -jnp.inf).reshape(-1)
-        ids = jnp.where(m, log.auction, 0).reshape(-1)
-        sv, si = jax.lax.sort((prices, ids.astype(jnp.uint32)), dimension=-1, num_keys=2)
-        return sv[-k:][::-1], si[-k:][::-1]
+        ids = jnp.where(m, log.auction, 0).reshape(-1).astype(jnp.uint32)
+        # ordered on the price's bits, as the lattice is (a float sort puts a
+        # subnormal level with 0.0); a bid repeated on one auction at one
+        # price counts once
+        sk, si = jax.lax.sort((float_to_ordered_u32(prices), ids), num_keys=2)
+        dup = jnp.concatenate([jnp.zeros(1, bool),
+                               (sk[1:] == sk[:-1]) & (si[1:] == si[:-1])])
+        pad = float_to_ordered_u32(jnp.float32(-jnp.inf))
+        sk, si = jax.lax.sort((jnp.where(dup, pad, sk), jnp.where(dup, 0, si)),
+                              num_keys=2)
+        return ordered_u32_to_float(sk[-k:][::-1]), si[-k:][::-1]
 
     return Query(
         name="q7",

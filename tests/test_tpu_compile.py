@@ -176,3 +176,28 @@ def test_keyed_q5_ring_stays_in_the_scatters_layout(topo, n_windows):
     ring_bytes = 16 * width_p * 4
     held = ring_bytes * (1 if n_windows == 1 else 4) + 100e6
     assert compiled.memory_analysis().temp_size_in_bytes <= held
+
+
+def test_q7_pipeline_compiles_at_the_cells_shapes(topo):
+    """The one-chip q7 cell's dataplane: 32 x 65,536 events per call, 10 s
+    tumbling windows, k = 8 over a 64-slot ring, delta sync every 4 batches.
+    Each batch's TopK fold pre-filters with the chip's TopK op, not a sort of
+    the batch, and sorts the batch's lanes only in the exact branch of its
+    conditional."""
+    from repro.launch.stream import build_pipeline
+    from repro.streaming.queries import make_q7
+
+    nb, epb = 32, 65_536
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",),
+                axis_types=(jax.sharding.AxisType.Auto,))
+    log = _event_log(NamedSharding(mesh, P("data")), (1, nb, epb))
+    query = make_q7(1, window_len=10_000, num_slots=64, k=8, topk_active=4)
+    text = build_pipeline(query, mesh, sync_every=4, n_windows=21).lower(log) \
+        .compile().as_text()
+    topk = [l for l in text.splitlines() if 'custom_call_target="TopK"' in l]
+    assert topk and all("/fold/scatter/prefilter/" in l for l in topk)
+    assert " conditional(" in text
+    # the exact join sorts each offset's k state pairs with every lane
+    lane_sorts = [l for l in text.splitlines()
+                  if " sort(" in l and f",{epb + 8}]" in l.partition(" = ")[2][:40]]
+    assert lane_sorts and all("/fold/scatter/" in l and "/exact/" in l for l in lane_sorts)
