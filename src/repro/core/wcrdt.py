@@ -10,7 +10,9 @@ A WCRDT wraps any CRDT from ``crdt.py`` with:
 Semantics (paper §4.2):
   - ``insert`` folds a *batch* of timestamped events into their window slots
     (one vectorized scatter instead of the paper's per-event loop — the TPU
-    adaptation of the hot path; see kernels/window_agg).
+    adaptation of the hot path; see kernels/window_agg).  Each slot's new
+    tenant is the newest window among the lanes it receives, a dense masked
+    max over the ring's ``W`` slots: no scatter.
   - ``increment_watermark`` raises this partition's progress entry.
   - ``global_watermark`` = min over all progress entries.
   - ``window_value(wid)`` is readable iff the global watermark has passed the
@@ -181,7 +183,12 @@ def insert(
     Batched Algorithm-1 INSERT: events below the partition's own watermark are
     dropped and counted (the paper raises an error); ring-slot reuse resets the
     slot's CRDT to zero first; events for already-evicted windows are dropped
-    and counted.
+    and counted.  A slot's tenant advances to the newest window among its
+    unmasked lanes, taken as a max over a ``[W, lanes]`` compare that the
+    compiler fuses into the reduce (a scatter-max serialises: every lane of a
+    batch lands in one of the few slots its windows occupy).  It assumes no
+    batch order or span: lanes beyond the ring's reach lose to their slot's
+    newest window and count as ERR_RING.
 
     Under an overlapping assigner (DESIGN.md §8) each event multi-emits into
     its ``windows_per_event`` windows: the batch expands into ``[B*K]`` lanes
@@ -217,12 +224,10 @@ def insert(
     # Sub-scopes of the dataplane's fold layer (docs/observability.md §7):
     # slot tenancy, ring-slot reset, and the scatter of the events.
     with jax.named_scope("tenancy"):
-        # Newest incoming window id per slot (masked lanes contribute NO_WID).
-        inc_wid = jnp.where(mask, wid, NO_WID)
-        seg_max = jax.ops.segment_max(
-            inc_wid, slot, num_segments=W, indices_are_sorted=False
-        )
-        seg_max = jnp.maximum(seg_max, NO_WID)  # empty segments -> -inf -> clamp
+        # Newest incoming window id per slot (see the docstring); masked
+        # lanes and empty slots give NO_WID.
+        hit = mask & (slot == jnp.arange(W, dtype=slot.dtype)[:, None])
+        seg_max = jnp.max(jnp.where(hit, wid, NO_WID), axis=1, initial=NO_WID)
         new_slot_wid = jnp.maximum(state.slot_wid, seg_max)
 
         # Reset slots whose tenant window advances.

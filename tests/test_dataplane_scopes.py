@@ -121,12 +121,12 @@ def test_no_op_holds_two_layers(compiled, pipeline):
 
 @pytest.mark.parametrize("pipeline", sorted(PIPELINES))
 def test_scatters_fold(compiled, pipeline):
-    """Every scatter, fused or not, folds: the insert's slot-tenancy
-    scatter-max and its event scatter."""
+    """Every scatter, fused or not, folds: the insert's event scatter.  The
+    slot tenancy is a dense per-slot max and scatters nothing."""
     scatters = [n for op, fused, n in compiled(pipeline)
                 if op == "scatter" or "scatter" in fused]
     assert scatters and all(layer(n) == "fold" for n in scatters), scatters
-    assert any("/fold/tenancy/" in n for n in scatters), scatters
+    assert not any("/fold/tenancy/" in n for n in scatters), scatters
     assert any("/fold/scatter/" in n for n in scatters), scatters
 
 

@@ -47,6 +47,13 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _tenancy_scatters(text):
+    """Scatter instructions, fused or not, in the insert's slot tenancy: the
+    dense per-slot max leaves none."""
+    return [l for l in text.splitlines()
+            if " scatter(" in l and "/fold/tenancy/" in l]
+
+
 def _event_log(sharding, shape):
     """EventBatch of ``shape`` operands, one per field, on ``sharding``."""
     from repro.streaming.events import EventBatch
@@ -133,6 +140,7 @@ def test_q4_delta_sync_pipeline_compiles_on_four_chips(topo, monkeypatch):
     text = compiled.as_text()
     assert "tpu_custom_call" in text  # the gated merge kernel is in
     assert "all-gather" in text  # and the deltas cross chips
+    assert not _tenancy_scatters(text)
     jax.clear_caches()
 
 
@@ -201,3 +209,4 @@ def test_q7_pipeline_compiles_at_the_cells_shapes(topo):
     lane_sorts = [l for l in text.splitlines()
                   if " sort(" in l and f",{epb + 8}]" in l.partition(" = ")[2][:40]]
     assert lane_sorts and all("/fold/scatter/" in l and "/exact/" in l for l in lane_sorts)
+    assert not _tenancy_scatters(text)
